@@ -5,8 +5,9 @@ distributed array with buffer swapping — the planner infers the halo
 exchange and the cross-launch dependencies automatically.
 
 Run:  PYTHONPATH=src python examples/quickstart.py
-(On a multi-device system the same code distributes; set
- XLA_FLAGS=--xla_force_host_platform_device_count=8 to try it on CPU.)
+On a TPU host this runs on the chips: one chip, or a mesh over all of them.
+On a CPU, XLA_FLAGS=--xla_force_host_platform_device_count=8 gives eight
+virtual devices to try the mesh path.
 """
 
 import sys

@@ -1,12 +1,2 @@
 """Reproduction of *Lightning: Scaling the GPU Programming Model Beyond a
-Single GPU*, grown into a multi-device jax system.
-
-Importing ``repro`` applies :mod:`repro.jax_compat`, which backfills the
-modern mesh API (``jax.sharding.AxisType`` / ``make_mesh(axis_types=…)``)
-on older jax releases so that every entry point — tests, subprocess
-harnesses, launch drivers — sees one uniform API.
-"""
-
-from repro import jax_compat as _jax_compat
-
-_jax_compat.apply()
+Single GPU*, grown into a multi-device jax system."""

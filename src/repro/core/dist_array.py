@@ -102,15 +102,46 @@ def make_array(
     mesh: Mesh | None = None,
     mesh_axes: Sequence[str] = (),
 ) -> DistributedArray:
-    """Place ``value`` according to ``dist`` (device_put with NamedSharding
-    when a mesh is available)."""
+    """Place ``value`` according to ``dist``.  On a mesh, host data goes
+    straight to its ``NamedSharding``: each device receives only its share,
+    never the whole array first."""
     arr = DistributedArray(
         name=name,
-        value=jnp.asarray(value),
+        value=value,
         dist=dist,
         mesh=mesh,
         mesh_axes=tuple(mesh_axes),
     )
     if mesh is not None and mesh.size > 1:
-        arr.value = jax.device_put(arr.value, arr.sharding())
+        arr.value = jax.device_put(value, arr.sharding())
+    else:
+        arr.value = jnp.asarray(value)
+    return arr
+
+
+def full_array(
+    name: str,
+    shape: Sequence[int],
+    fill: Any,
+    dtype: Any,
+    dist: Distribution,
+    mesh: Mesh | None = None,
+    mesh_axes: Sequence[str] = (),
+) -> DistributedArray:
+    """A constant-filled array created in place: on a mesh each device
+    materializes only its own shard."""
+    shape = tuple(int(d) for d in shape)
+    arr = DistributedArray(
+        name=name,
+        value=jax.ShapeDtypeStruct(shape, dtype),
+        dist=dist,
+        mesh=mesh,
+        mesh_axes=tuple(mesh_axes),
+    )
+    if mesh is not None and mesh.size > 1:
+        build = jax.jit(jnp.full, static_argnums=(0, 2),
+                        out_shardings=arr.sharding())
+        arr.value = build(shape, fill, jnp.dtype(dtype))
+    else:
+        arr.value = jnp.full(shape, fill, dtype)
     return arr

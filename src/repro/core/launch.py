@@ -39,24 +39,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.8 moved shard_map out of experimental (and renamed
-    # check_rep -> check_vma); support both.
-    from jax import shard_map as _shard_map_impl
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_rep=False):
-        return _shard_map_impl(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_rep,
-        )
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
 from repro.obs.metrics import MetricsRegistry, default_registry
 from repro.obs.trace import NULL_TRACER
 
 from . import annotations as ann_mod
 from .annotations import Annotation, REDUCE as MODE_REDUCE
-from .dist_array import DistributedArray, make_array
+from .dist_array import DistributedArray, full_array, make_array
 from .distributions import Distribution, ReplicatedDist
 from .faults import FaultInjector, RecoveryPolicy
 from .ndrange import Region
@@ -191,13 +179,21 @@ class Context:
         )
 
     def zeros(self, shape, dtype=jnp.float32, dist=None, name=None):
-        return self.array(jnp.zeros(shape, dtype), dist, name)
+        return self.full(shape, 0, dtype, dist, name)
 
     def ones(self, shape, dtype=jnp.float32, dist=None, name=None):
-        return self.array(jnp.ones(shape, dtype), dist, name)
+        return self.full(shape, 1, dtype, dist, name)
 
     def full(self, shape, fill, dtype=jnp.float32, dist=None, name=None):
-        return self.array(jnp.full(shape, fill, dtype), dist, name)
+        return full_array(
+            name or self._fresh_name("arr"),
+            shape,
+            fill,
+            dtype,
+            dist or ReplicatedDist(),
+            mesh=self.mesh,
+            mesh_axes=self.mesh_axes,
+        )
 
     # -- launch ------------------------------------------------------------------
 
@@ -437,12 +433,12 @@ class Context:
                 final.append(o)
             return tuple(final)
 
-        fn = shard_map(
+        fn = jax.shard_map(
             shard_body,
             mesh=mesh,
             in_specs=tuple(in_specs[n] for n in args),
             out_specs=tuple(out_specs[n] for n in written),
-            check_rep=False,
+            check_vma=False,
         )
         out_vals = fn(*[a.value for a in args.values()])
         return dict(zip(written, out_vals)), in_specs, out_specs

@@ -17,7 +17,7 @@ rules imply):
   cross-pod (DCN) axes, so the expensive hop carries already-reduced data.
 
 All functions are written against named axes only — they run under
-``jax.experimental.shard_map.shard_map`` on any mesh, including the fake
+``jax.shard_map`` on any mesh, including the fake
 host-device meshes of the test harness.
 """
 

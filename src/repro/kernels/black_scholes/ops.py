@@ -5,7 +5,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from ..common import interpret_default, round_up
+from ..common import LANE, interpret_default, round_up
 from .kernel import black_scholes_pallas
 from .ref import black_scholes_ref
 
@@ -27,7 +27,7 @@ def black_scholes(
         )
     interpret = interpret_default() if interpret is None else interpret
     (n,) = price.shape
-    blk = min(block, max(1, n))
+    blk = min(block, round_up(max(1, n), LANE))
     target = round_up(n, blk)
     if target != n:
         pad = target - n

@@ -17,7 +17,7 @@ def cluster_sums(
     nrow_clusters: int,
     ncol_clusters: int,
     *,
-    block_n: int = 1024,
+    block_n: int = 512,
     interpret: bool | None = None,
     use_ref: bool = False,
 ) -> jax.Array:
@@ -27,16 +27,16 @@ def cluster_sums(
         )
     interpret = interpret_default() if interpret is None else interpret
     n, m = z.shape
-    blk = min(block_n, n)
-    target = round_up(n, blk)
-    if target != n:
-        pad = target - n
-        z = jnp.concatenate([z, jnp.zeros((pad, m), z.dtype)])
-        row_assign = jnp.concatenate(
-            [row_assign, jnp.zeros((pad,), row_assign.dtype)]
-        )  # pad rows are all-zero → contribute nothing
-    col_onehot = jax.nn.one_hot(col_assign, ncol_clusters, dtype=z.dtype)
+    bn, bm = min(block_n, n), min(1024, m)  # rows, columns per tile
+    n_pad, m_pad = round_up(n, bn) - n, round_up(m, bm) - m
+    if n_pad or m_pad:
+        # Pad entries are zero, so whichever cluster they land in (0) gains
+        # nothing.
+        z = jnp.pad(z, ((0, n_pad), (0, m_pad)))
+        row_assign = jnp.pad(row_assign, (0, n_pad))
+        col_assign = jnp.pad(col_assign, (0, m_pad))
     return cluster_sums_pallas(
-        z, row_assign, col_onehot,
-        nrow_clusters=nrow_clusters, block_n=blk, interpret=interpret,
+        z, row_assign, col_assign,
+        nrow_clusters=nrow_clusters, ncol_clusters=ncol_clusters,
+        block_n=bn, block_m=bm, interpret=interpret,
     )

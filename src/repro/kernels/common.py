@@ -1,16 +1,18 @@
 """Shared helpers for the Pallas TPU kernels.
 
-Every kernel in this package targets TPU (``pl.pallas_call`` with explicit
-``BlockSpec`` VMEM tiling, MXU-aligned block shapes) and validates on CPU via
-``interpret=True``, which executes the kernel body in Python.  The ``ops.py``
-wrapper of each kernel auto-selects interpret mode off-TPU so the whole test
-suite runs in this container.
+Every kernel in this package is compiled by Mosaic for the TPU
+(``pl.pallas_call`` with explicit ``BlockSpec`` VMEM tiling, MXU-aligned
+block shapes); that is how they run.  Off the TPU, the ``ops.py`` wrapper of
+each kernel falls back to ``interpret=True``, which executes the kernel body
+with XLA on the CPU, so the CPU test suite can check every kernel against
+its ``ref.py`` oracle.  ``tests/test_tpu_compile.py`` compiles each kernel
+for a described TPU v5e to catch what interpret mode cannot (tiling rules,
+VMEM limits, lowerings).
 """
 
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -45,20 +47,6 @@ def pad_to(x: jax.Array, axis: int, multiple: int, value=0) -> jax.Array:
     return jnp.pad(x, pads, constant_values=value)
 
 
-# TPU tiling constants (v5e): MXU is 128x128, VREG lane width 128, sublane 8.
+# TPU tiling constants (v5e): VREG lane width 128, sublane 8.
 LANE = 128
 SUBLANE = 8
-MXU = 128
-
-#: Hardware constants used by roofline estimates (TPU v5e).
-PEAK_FLOPS_BF16 = 197e12
-PEAK_HBM_BW = 819e9
-VMEM_BYTES = 128 * 1024 * 1024  # ~128 MiB VMEM per core on v5e
-
-
-def vmem_fits(*block_shapes_dtypes, budget: float = 0.7) -> bool:
-    """Sanity helper: do the given (shape, dtype) blocks fit in VMEM?"""
-    total = 0
-    for shape, dtype in block_shapes_dtypes:
-        total += math.prod(shape) * jnp.dtype(dtype).itemsize
-    return total <= budget * VMEM_BYTES

@@ -26,9 +26,10 @@ NEG_INF = -1e30
 
 
 def _decode_kernel(
-    q_ref, k_ref, v_ref, len_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
+    len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
     *, scale: float, block_k: int, kv_steps: int,
 ):
+    bi = pl.program_id(0)
     ki = pl.program_id(2)
 
     @pl.when(ki == 0)
@@ -40,7 +41,7 @@ def _decode_kernel(
     q = q_ref[0, 0]  # (group, d) — all query heads of one kv head group
     k = k_ref[0, 0]  # (block_k, d)
     v = v_ref[0, 0]  # (block_k, d)
-    valid_len = len_ref[0]
+    valid_len = len_ref[bi]  # scalar-prefetched into SMEM
 
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (hq, bk)
     k_pos = ki * block_k + jax.lax.broadcasted_iota(
@@ -62,7 +63,7 @@ def _decode_kernel(
     def _flush():
         l = jnp.maximum(l_ref[...], 1e-30)
         o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_ref[...] + jnp.log(l))[:, 0]
+        lse_ref[0, 0] = m_ref[...] + jnp.log(l)
 
 
 @functools.partial(
@@ -91,30 +92,40 @@ def decode_attention_pallas(
     q_grouped = q.reshape(b, hkv, group, d)
     grid = (b, hkv, kv_steps)
 
+    # kv_len is scalar-prefetched (SMEM), so index maps take it as a
+    # trailing argument; the lse leaves as (group, 1) columns, which span
+    # the array's last two dims as TPU tiling requires.
     out, lse = pl.pallas_call(
         functools.partial(
             _decode_kernel, scale=scale, block_k=block_k, kv_steps=kv_steps
         ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, 1, group, d), lambda b_, h, j: (b_, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b_, h, j: (b_, h, j, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b_, h, j: (b_, h, j, 0)),
-            pl.BlockSpec((1,), lambda b_, h, j: (b_,)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, 1, group, d), lambda b_, h, j: (b_, h, 0, 0)),
-            pl.BlockSpec((1, 1, group), lambda b_, h, j: (b_, h, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, 1, group, d),
+                             lambda b_, h, j, lens: (b_, h, 0, 0)),
+                pl.BlockSpec((1, 1, block_k, d),
+                             lambda b_, h, j, lens: (b_, h, j, 0)),
+                pl.BlockSpec((1, 1, block_k, d),
+                             lambda b_, h, j, lens: (b_, h, j, 0)),
+            ],
+            out_specs=(
+                pl.BlockSpec((1, 1, group, d),
+                             lambda b_, h, j, lens: (b_, h, 0, 0)),
+                pl.BlockSpec((1, 1, group, 1),
+                             lambda b_, h, j, lens: (b_, h, 0, 0)),
+            ),
+            scratch_shapes=[
+                pltpu.VMEM((group, 1), jnp.float32),
+                pltpu.VMEM((group, 1), jnp.float32),
+                pltpu.VMEM((group, d), jnp.float32),
+            ],
         ),
         out_shape=(
             jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
-            jax.ShapeDtypeStruct((b, hkv, group), jnp.float32),
+            jax.ShapeDtypeStruct((b, hkv, group, 1), jnp.float32),
         ),
-        scratch_shapes=[
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, 1), jnp.float32),
-            pltpu.VMEM((group, d), jnp.float32),
-        ],
         interpret=interpret,
-    )(q_grouped, k, v, kv_len)
+    )(kv_len, q_grouped, k, v)
     return out.reshape(b, hq, d), lse.reshape(b, hq)

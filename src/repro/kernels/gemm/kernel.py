@@ -27,8 +27,13 @@ def _gemm_kernel(a_ref, b_ref, o_ref, acc_ref, *, k_steps: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+    # bf16 products are exact in the f32 accumulator, and Mosaic takes no
+    # fp32 contract precision for bf16 operands: only f32 operands follow
+    # the configured matmul precision.
+    precision = None if a_ref.dtype == jnp.float32 else jax.lax.Precision.DEFAULT
     acc_ref[...] += jnp.dot(
-        a_ref[...], b_ref[...], preferred_element_type=jnp.float32
+        a_ref[...], b_ref[...], preferred_element_type=jnp.float32,
+        precision=precision,
     )
 
     @pl.when(k == k_steps - 1)

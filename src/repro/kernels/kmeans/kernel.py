@@ -7,8 +7,11 @@ does the heavy lifting, and the per-block partial sums use a one-hot matmul
 so the reduce(+) semantics of the annotation is realized as
 partials-then-tree exactly like Lightning's planner does.
 
-Outputs are *per-block partials*: sums (blocks, k, f) and counts (blocks, k).
-The caller (ops/launch) reduces over the leading axis.
+Points enter feature-major, ``(f, n)``: with few features a point-major
+block would pad its ``f`` lanes to 128, while here points fill the lanes and
+the transpose of a narrow ``(n, f)`` array is the layout XLA already keeps
+it in.  Outputs are *per-block partials*: sums (blocks, k, f) and counts
+(blocks, k, 1).  The caller (ops/launch) reduces over the leading axis.
 """
 
 from __future__ import annotations
@@ -22,22 +25,26 @@ from jax.experimental import pallas as pl
 from ..common import cdiv
 
 
-def _kmeans_kernel(p_ref, c_ref, sums_ref, counts_ref):
-    p = p_ref[...]  # (block, f)
+def _kmeans_kernel(pt_ref, c_ref, sums_ref, counts_ref):
+    pt = pt_ref[...]  # (f, block)
     c = c_ref[...]  # (k, f)
-    d2 = (
-        jnp.sum(p * p, axis=1, keepdims=True)
-        - 2.0 * jnp.dot(p, c.T, preferred_element_type=jnp.float32)
-        + jnp.sum(c * c, axis=1)[None, :]
-    )
-    assign = jnp.argmin(d2, axis=1)  # (block,)
     k = c.shape[0]
-    onehot = (assign[:, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (p.shape[0], k), 1)).astype(p.dtype)
-    sums_ref[0, ...] = jnp.dot(
-        onehot.T, p, preferred_element_type=jnp.float32
+    d2 = (
+        jnp.sum(pt * pt, axis=0, keepdims=True)
+        - 2.0 * jnp.dot(c, pt, preferred_element_type=jnp.float32)
+        + jnp.sum(c * c, axis=1, keepdims=True)
+    )  # (k, block)
+    # argmin over centroids (first index of the minimum, like jnp.argmin).
+    rows = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 0)
+    best = jnp.min(d2, axis=0, keepdims=True)
+    assign = jnp.min(jnp.where(d2 == best, rows, k), axis=0, keepdims=True)
+    onehot = (rows == assign).astype(pt.dtype)  # (k, block)
+    sums_ref[...] = jax.lax.dot_general(
+        onehot, pt, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
     ).astype(sums_ref.dtype)
-    counts_ref[0, ...] = jnp.sum(onehot, axis=0).astype(counts_ref.dtype)
+    counts_ref[...] = jnp.sum(onehot, axis=1, keepdims=True).astype(
+        counts_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -45,7 +52,7 @@ def kmeans_pallas(
     points: jax.Array,  # (n, f)
     centroids: jax.Array,  # (k, f)
     *,
-    block: int = 4096,
+    block: int = 8192,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     n, f = points.shape
@@ -54,20 +61,21 @@ def kmeans_pallas(
     block = min(block, n)
     assert n % block == 0, "ops.py pads points"
     blocks = cdiv(n, block)
-    return pl.pallas_call(
+    sums, counts = pl.pallas_call(
         _kmeans_kernel,
         grid=(blocks,),
         in_specs=[
-            pl.BlockSpec((block, f), lambda i: (i, 0)),
+            pl.BlockSpec((f, block), lambda i: (0, i)),
             pl.BlockSpec((k, f), lambda i: (0, 0)),
         ],
         out_specs=(
-            pl.BlockSpec((1, k, f), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
+            pl.BlockSpec((None, k, f), lambda i: (i, 0, 0)),
+            pl.BlockSpec((None, k, 1), lambda i: (i, 0, 0)),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((blocks, k, f), jnp.float32),
-            jax.ShapeDtypeStruct((blocks, k), jnp.float32),
+            jax.ShapeDtypeStruct((blocks, k, 1), jnp.float32),
         ),
         interpret=interpret,
-    )(points, centroids)
+    )(points.T, centroids)
+    return sums, counts[..., 0]

@@ -14,7 +14,7 @@ def kmeans_assign_reduce(
     points: jax.Array,
     centroids: jax.Array,
     *,
-    block: int = 4096,
+    block: int = 8192,
     interpret: bool | None = None,
     use_ref: bool = False,
 ) -> tuple[jax.Array, jax.Array]:

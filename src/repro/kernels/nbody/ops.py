@@ -13,8 +13,8 @@ from .ref import SOFTENING2, nbody_forces_ref, nbody_step_ref
 def nbody_forces(
     posm: jax.Array,
     *,
-    block_i: int = 1024,
-    block_j: int = 1024,
+    block_i: int = 512,
+    block_j: int = 128,
     softening2: float = SOFTENING2,
     interpret: bool | None = None,
     use_ref: bool = False,

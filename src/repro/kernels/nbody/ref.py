@@ -15,11 +15,17 @@ import jax.numpy as jnp
 SOFTENING2 = 1e-3
 
 
-def nbody_forces_ref(posm: jax.Array, softening2: float = SOFTENING2) -> jax.Array:
-    """Accelerations (n, 3)."""
+def nbody_forces_ref(
+    posm: jax.Array,
+    softening2: float = SOFTENING2,
+    targets: jax.Array | None = None,
+) -> jax.Array:
+    """Accelerations (n, 3) of every body, or of the ``(m, 4)`` bodies in
+    ``targets`` (m, 3) under the pull of all of ``posm``."""
     pos = posm[:, :3]
     mass = posm[:, 3]
-    d = pos[None, :, :] - pos[:, None, :]  # (i, j, 3): p_j - p_i
+    tgt = pos if targets is None else targets[:, :3]
+    d = pos[None, :, :] - tgt[:, None, :]  # (i, j, 3): p_j - p_i
     dist2 = jnp.sum(d * d, axis=-1) + softening2
     inv_d3 = jax.lax.rsqrt(dist2) / dist2  # 1 / dist^3
     return jnp.einsum("ij,ijk->ik", mass[None, :] * inv_d3, d)

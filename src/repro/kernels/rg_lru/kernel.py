@@ -65,6 +65,11 @@ def rg_lru_pallas(
     assert t % block_t == 0 and d % block_d == 0, "ops.py pads"
     t_steps = cdiv(t, block_t)
     grid = (b, cdiv(d, block_d), t_steps)
+    # States are carried (B, 1, D) so a (1, block_d) block spans the
+    # array's full second-minor dim, as TPU tiling requires.  The scan reads
+    # and writes one time row at a time, which Mosaic addresses only in
+    # 32-bit types (bf16 packs two rows to a sublane), so it runs in f32.
+    state = pl.BlockSpec((None, 1, block_d), lambda b_, j, i: (b_, 0, j))
 
     out, h_final = pl.pallas_call(
         functools.partial(_rg_lru_kernel, block_t=block_t, t_steps=t_steps),
@@ -72,17 +77,17 @@ def rg_lru_pallas(
         in_specs=[
             pl.BlockSpec((1, block_t, block_d), lambda b_, j, i: (b_, i, j)),
             pl.BlockSpec((1, block_t, block_d), lambda b_, j, i: (b_, i, j)),
-            pl.BlockSpec((1, block_d), lambda b_, j, i: (b_, j)),
+            state,
         ],
         out_specs=(
             pl.BlockSpec((1, block_t, block_d), lambda b_, j, i: (b_, i, j)),
-            pl.BlockSpec((1, block_d), lambda b_, j, i: (b_, j)),
+            state,
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((b, t, d), gx.dtype),
-            jax.ShapeDtypeStruct((b, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, t, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, d), jnp.float32),
         ),
         scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
         interpret=interpret,
-    )(log_a, gx, h0)
-    return out, h_final
+    )(log_a.astype(jnp.float32), gx.astype(jnp.float32), h0[:, None, :])
+    return out.astype(gx.dtype), h_final[:, 0, :]

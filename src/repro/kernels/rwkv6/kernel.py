@@ -67,10 +67,15 @@ def wkv6_pallas(
 ) -> tuple[jax.Array, jax.Array]:
     b, h, t, dk = r.shape
     dv = v.shape[-1]
+    dtype = r.dtype
     block_t = min(block_t, t)
     assert t % block_t == 0, "ops.py pads time"
     t_steps = cdiv(t, block_t)
     grid = (b, h, t_steps)
+    # The recurrence reads and writes one time row at a time, which Mosaic
+    # addresses only in 32-bit types (bf16 packs two rows to a sublane), so
+    # the kernel runs in f32.
+    r, k, v, w, u = (x.astype(jnp.float32) for x in (r, k, v, w, u))
 
     out, s_final = pl.pallas_call(
         functools.partial(_wkv6_kernel, block_t=block_t, t_steps=t_steps),
@@ -80,7 +85,9 @@ def wkv6_pallas(
             pl.BlockSpec((1, 1, block_t, dk), lambda b_, h_, i: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, block_t, dv), lambda b_, h_, i: (b_, h_, i, 0)),
             pl.BlockSpec((1, 1, block_t, dk), lambda b_, h_, i: (b_, h_, i, 0)),
-            pl.BlockSpec((1, dk), lambda b_, h_, i: (h_, 0)),
+            # u is carried (H, 1, K): a (1, K) block spans the array's last
+            # two dims, as TPU tiling requires of a one-row block.
+            pl.BlockSpec((None, 1, dk), lambda b_, h_, i: (h_, 0, 0)),
             pl.BlockSpec((1, 1, dk, dv), lambda b_, h_, i: (b_, h_, 0, 0)),
         ],
         out_specs=(
@@ -88,10 +95,10 @@ def wkv6_pallas(
             pl.BlockSpec((1, 1, dk, dv), lambda b_, h_, i: (b_, h_, 0, 0)),
         ),
         out_shape=(
-            jax.ShapeDtypeStruct((b, h, t, dv), r.dtype),
+            jax.ShapeDtypeStruct((b, h, t, dv), jnp.float32),
             jax.ShapeDtypeStruct((b, h, dk, dv), jnp.float32),
         ),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
         interpret=interpret,
-    )(r, k, v, w, u, s0)
-    return out, s_final
+    )(r, k, v, w, u[:, None, :], s0)
+    return out.astype(dtype), s_final

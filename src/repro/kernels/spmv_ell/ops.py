@@ -15,7 +15,7 @@ def spmv_ell(
     cols: jax.Array,
     x: jax.Array,
     *,
-    block: int = 2048,
+    block: int = 8192,
     interpret: bool | None = None,
     use_ref: bool = False,
 ) -> jax.Array:

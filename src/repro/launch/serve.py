@@ -16,6 +16,7 @@ import numpy as np
 from repro.configs import ARCHS, get_config, get_smoke_config
 from repro.models import init_params
 from repro.serve.engine import Request, ServeEngine
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def run_serving(
@@ -43,9 +44,15 @@ def run_serving(
         ))
     done = engine.run()
     dt = time.time() - t0
+    statuses: dict[str, int] = {}
+    for req in done:
+        statuses[req.status] = statuses.get(req.status, 0) + 1
     return {
         "arch": cfg.name,
         "completed": len(done),
+        "statuses": statuses,
+        "errors": engine.stats["errors"],
+        "error_messages": sorted({r.error for r in done if r.error}),
         "decode_tokens": engine.stats["decode_tokens"],
         "prefill_tokens": engine.stats["prefill_tokens"],
         "wall_s": round(dt, 3),
@@ -65,6 +72,7 @@ def main():
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--slots", type=int, default=4)
     args = ap.parse_args()
+    enable_compile_cache()
     print(json.dumps(run_serving(
         args.arch, smoke=args.smoke, requests=args.requests,
         prompt_len=args.prompt_len, max_new=args.max_new, slots=args.slots,

@@ -30,6 +30,7 @@ from repro.dist.fault import HeartbeatMonitor, StragglerMonitor, TrainSupervisor
 from repro.obs.metrics import MetricsRegistry, default_registry
 from repro.obs.trace import NULL_TRACER
 from repro.train.train_loop import init_train_state, make_train_step
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def run_training(
@@ -166,6 +167,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     result = run_training(
         args.arch, smoke=args.smoke, steps=args.steps, batch=args.batch,
         seq=args.seq, microbatches=args.microbatches,

@@ -47,6 +47,7 @@ class Request:
     output: list[int] = dataclasses.field(default_factory=list)
     done: bool = False
     status: str = "pending"  # -> "ok" | "timed_out" | "error"
+    error: str | None = None  # repr of the exception behind "error"
 
 
 class ServeEngine:
@@ -150,7 +151,8 @@ class ServeEngine:
                                           rid=req.rid, slot=s,
                                           prompt_len=len(req.prompt)):
                         logits, pstate = self._prefill_with_retry(req)
-                except Exception:  # noqa: BLE001 — retries exhausted
+                except Exception as exc:  # noqa: BLE001 — retries exhausted
+                    req.error = repr(exc)
                     self.stats["errors"] += 1
                     self._finish(s, req, "error")  # slot stays free
                     continue

@@ -14,16 +14,6 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 if SRC not in sys.path:
     sys.path.insert(0, SRC)
 
-# Property tests prefer the real hypothesis package; environments without it
-# (no network, hermetic CI images) fall back to the seeded-sampling shim so
-# the suite still collects and the properties still get exercised.
-try:
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    from repro._compat import hypothesis_fallback
-
-    hypothesis_fallback.install()
-
 
 @pytest.fixture(scope="session")
 def rng():

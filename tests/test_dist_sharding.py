@@ -170,7 +170,7 @@ class TestCollectiveSpans:
     stream through the module tracer installed with ``set_tracer``."""
 
     def test_ring_allreduce_emits_dist_span(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from repro.dist import ring_allreduce, set_tracer
         from repro.obs.trace import Tracer
 

@@ -83,6 +83,23 @@ def test_hotspot_sweep(shape, block):
                                rtol=2e-5, atol=2e-4)
 
 
+@pytest.mark.parametrize("rows,cols,block", [(32, 128, 8), (27, 128, 8)])
+def test_hotspot_halo_rows_match_whole_grid(rows, cols, block):
+    """Slabs stepped with their neighbours' rows as ``up``/``down`` (a
+    halo exchange) reassemble the step of the whole grid."""
+    t = f32(3 * rows, cols, scale=30.0) + 60.0
+    p = f32(3 * rows, cols, scale=0.5) ** 2
+    want = np.asarray(hotspot_step_ref(t, p))
+    for lo in range(0, 3 * rows, rows):
+        hi = lo + rows
+        up = t[lo - 1:lo] if lo else None
+        down = t[hi:hi + 1] if hi < 3 * rows else None
+        got = hotspot_step(t[lo:hi], p[lo:hi], up=up, down=down,
+                           block_rows=block)
+        np.testing.assert_allclose(np.asarray(got), want[lo:hi],
+                                   rtol=2e-5, atol=2e-4)
+
+
 def test_hotspot_iterated_stable():
     t = f32(64, 128, scale=10.0) + 70.0
     p = jnp.abs(f32(64, 128, scale=0.3))
@@ -202,6 +219,15 @@ def test_nbody_sweep(n, bi, bj):
                                rtol=5e-4, atol=5e-4)
 
 
+def test_nbody_ref_targets_are_rows_of_the_whole():
+    posm = RNG.rand(96, 4).astype(np.float32)
+    rows = np.array([0, 5, 50, 95])
+    whole = nbody_forces_ref(jnp.asarray(posm))
+    some = nbody_forces_ref(jnp.asarray(posm), targets=jnp.asarray(posm[rows]))
+    np.testing.assert_allclose(np.asarray(some), np.asarray(whole)[rows],
+                               rtol=1e-6, atol=1e-6)
+
+
 def test_nbody_momentum_conservation():
     """Equal masses: total force ≈ 0 (Newton's third law)."""
     n = 128
@@ -240,7 +266,8 @@ def test_correlator_hermitian():
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("n,m,R,C", [(500, 64, 5, 4), (256, 128, 8, 8)])
+@pytest.mark.parametrize("n,m,R,C", [(500, 64, 5, 4), (256, 128, 8, 8),
+                                     (40, 1100, 3, 5)])
 def test_cluster_sums_sweep(n, m, R, C):
     z = jnp.abs(f32(n, m))
     ra = jnp.asarray(RNG.randint(0, R, n).astype(np.int32))

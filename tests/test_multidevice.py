@@ -63,11 +63,52 @@ print("LAUNCH-OK")
 
 
 @pytest.mark.slow
+def test_placement_puts_each_share_on_its_device():
+    """Host data and constant fills land on a 4-device mesh shard by shard:
+    each device holds only its rows, and a launch over them equals the
+    same launch on one device."""
+    out = run_with_devices("""
+import jax, jax.numpy as jnp, numpy as np
+from repro.core import *
+
+mesh = jax.make_mesh((4,), ("data",))
+ctx = Context(mesh=mesh)
+x = np.arange(64 * 8, dtype=np.float32).reshape(64, 8)
+arrays = {
+    "x": (ctx.array(x, dist=RowDist(), name="x"), x),
+    "z": (ctx.zeros((64, 8), dist=RowDist(), name="z"), np.zeros((64, 8))),
+    "f": (ctx.full((64, 8), 2.5, dist=RowDist(), name="f"),
+          np.full((64, 8), 2.5)),
+}
+for name, (arr, want) in arrays.items():
+    shards = arr.value.addressable_shards
+    assert {s.device for s in shards} == set(mesh.devices.flat), name
+    for s in shards:
+        assert s.data.shape == (16, 8), (name, s.data.shape)
+        assert s.data.devices() == {s.device}, name
+        np.testing.assert_array_equal(np.asarray(s.data), want[s.index])
+
+k = KernelDef.define(
+    "axpy", lambda v, _: {"z": 3.0 * v["x"] + v["f"]},
+    "global [i, j] => read x[i,j], read f[i,j], write z[i,j]")
+res = ctx.launch(k, grid=(64, 8), args={n: a for n, (a, _) in arrays.items()})
+one = Context()
+res1 = one.launch(k, grid=(64, 8), args={
+    "x": one.array(x), "f": one.full((64, 8), 2.5), "z": one.zeros((64, 8))})
+np.testing.assert_array_equal(np.asarray(res["z"].value),
+                              np.asarray(res1["z"].value))
+np.testing.assert_array_equal(np.asarray(res["z"].value), 3.0 * x + 2.5)
+print("PLACEMENT-OK")
+""", n_devices=4)
+    assert "PLACEMENT-OK" in out
+
+
+@pytest.mark.slow
 def test_collective_matmuls_multidevice():
     out = run_with_devices("""
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.dist.collectives import (
     ring_allgather_matmul, hierarchical_grad_allreduce)
@@ -82,7 +123,7 @@ w = rng.rand(64, 32).astype(np.float32)
 ring = shard_map(
     partial(ring_allgather_matmul, axis_name="data"),
     mesh=mesh, in_specs=(P(None, "data"), P("data", None)),
-    out_specs=P(), check_rep=False)
+    out_specs=P(), check_vma=False)
 got = ring(jnp.asarray(x), jnp.asarray(w))
 np.testing.assert_allclose(np.asarray(got), x @ w, rtol=1e-4)
 
@@ -96,9 +137,9 @@ def hier_fn(t):
 for fn in (ref_fn, hier_fn):
     pass
 ref = shard_map(ref_fn, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                check_rep=False)(g)
+                check_vma=False)(g)
 hier = shard_map(hier_fn, mesh=mesh, in_specs=(P(),), out_specs=P(),
-                 check_rep=False)(g)
+                 check_vma=False)(g)
 np.testing.assert_allclose(np.asarray(ref["w"]), np.asarray(hier["w"]),
                            rtol=1e-5)
 print("COLLECTIVES-OK")
@@ -165,7 +206,7 @@ def test_compressed_psum_multidevice():
     out = run_with_devices("""
 import jax, jax.numpy as jnp, numpy as np
 from functools import partial
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.optim.compression import compressed_psum, ErrorFeedback
 
@@ -179,7 +220,7 @@ def body(g):
     return out["g"]
 
 fn = shard_map(body, mesh=mesh, in_specs=(P("data", None),),
-               out_specs=P(None), check_rep=False)
+               out_specs=P(None), check_vma=False)
 got = np.asarray(fn(jnp.asarray(g_global)))[0]
 want = g_global.sum(axis=0)
 # int8 quantization: bounded relative error vs true sum
