@@ -39,8 +39,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.obs.compiles import CompileWatch
 from repro.obs.metrics import MetricsRegistry, default_registry
-from repro.obs.trace import NULL_TRACER
+from repro.obs.trace import _NULL_SPAN, NULL_TRACER
 
 from . import annotations as ann_mod
 from .annotations import Annotation, REDUCE as MODE_REDUCE
@@ -120,9 +121,10 @@ class Context:
         plan_cache: bool = True,
     ):
         self.mesh = mesh
-        # Observability: launches emit plan/execute spans on the ``driver``
-        # stream and count launches/retries/recoveries on the registry
-        # (resolved lazily so ``use_registry`` redirects us too).
+        # Observability: launches emit plan/launch/execute spans (and JAX's
+        # jax:* compile stages) on the ``driver`` stream and count launches,
+        # retries, recoveries, compiled programs and compile seconds on the
+        # registry (resolved lazily so ``use_registry`` redirects us too).
         self.tracer = tracer or NULL_TRACER
         self._registry = registry
         # Fault tolerance: with an injector threaded in, failed kernel
@@ -215,32 +217,55 @@ class Context:
         scalars = dict(scalars or {})
         arrays = {name: a.meta() for name, a in args.items()}
 
-        with self.tracer.span(f"plan:{kernel.name}", stream="driver",
-                              cat="sched", grid=list(grid)):
+        tracer = self.tracer
+        traced = tracer.enabled
+        registry = self.registry
+        # Every span of one launch carries its id as ``launch``.  With the
+        # tracer off, no span, event or args dict is built.
+        lid = tracer.new_id() if traced else None
+        with (tracer.span(f"plan:{kernel.name}", stream="driver", cat="sched",
+                          grid=list(grid), launch=lid)
+              if traced else _NULL_SPAN):
             plan = self.planner.plan_launch(
                 kernel.name, kernel.annotation, grid, work_dist, arrays,
                 block_shape=block_shape, plan=self.plan,
             )
         comm = {a.array: a.pattern for a in plan.args}
-        self.registry.counter("launch.count").labels(
-            kernel=kernel.name).inc()
+        registry.counter("launch.count").labels(kernel=kernel.name).inc()
 
-        with self.tracer.span(f"launch:{kernel.name}", stream="driver",
-                              cat="compute", grid=list(grid),
-                              devices=self.num_devices):
-            if self.mesh is None or self.mesh.size == 1:
-                outputs = self._with_recovery(
-                    kernel, lambda: self._execute_single(kernel, grid, args,
-                                                         scalars)
-                )
-                in_specs = {n: P() for n in args}
-                out_specs = {n: P() for n in outputs}
-            else:
-                outputs, in_specs, out_specs = self._with_recovery(
-                    kernel, lambda: self._execute_mesh(kernel, grid, args,
-                                                       scalars, plan,
-                                                       work_dist)
-                )
+        launch_span = (tracer.span(f"launch:{kernel.name}", stream="driver",
+                                   cat="compute", grid=list(grid),
+                                   devices=self.num_devices, launch=lid)
+                       if traced else _NULL_SPAN)
+        with launch_span:
+            # JAX's trace/lower/compile events while the launch executes:
+            # counted always, recorded as jax:* child spans when traced.
+            with CompileWatch(tracer, launch=lid) as seen, (
+                    tracer.span(f"execute:{kernel.name}", stream="driver",
+                                cat="compute", launch=lid)
+                    if traced else _NULL_SPAN):
+                if self.mesh is None or self.mesh.size == 1:
+                    outputs = self._with_recovery(
+                        kernel, lambda: self._execute_single(kernel, grid,
+                                                             args, scalars)
+                    )
+                    in_specs = {n: P() for n in args}
+                    out_specs = {n: P() for n in outputs}
+                else:
+                    outputs, in_specs, out_specs = self._with_recovery(
+                        kernel, lambda: self._execute_mesh(kernel, grid, args,
+                                                           scalars, plan,
+                                                           work_dist)
+                    )
+            compile_s = seen.compile_s
+            registry.counter("launch.programs").labels(
+                kernel=kernel.name).inc(seen.programs)
+            registry.counter("launch.compile_s").labels(
+                kernel=kernel.name).inc(compile_s)
+            if traced:
+                launch_span.add(programs=seen.programs,
+                                cache_loads=seen.cache_loads,
+                                traces=seen.traces, compile_s=compile_s)
 
         self.records.append(
             LaunchRecord(plan=plan, in_specs=in_specs, out_specs=out_specs,

@@ -25,20 +25,36 @@ Zero cost when disabled: :data:`NULL_TRACER` answers every ``span()`` with
 one shared no-op singleton — no span objects, no event dicts, no clock
 reads.  Call sites guard bulk work with ``if tracer.enabled:``.
 
+Spans form a tree: each one opened with :meth:`Tracer.span` records an
+``id`` and the ``parent`` id of the span open on the same thread when it
+started (``None`` at the root).  Both sit in the raw event and in the
+exported Chrome ``args``.
+
 With no clock injected the tracer runs on a **logical clock** (one
 microsecond per read): ordering is preserved and two identical runs
 produce byte-identical traces.  Pass ``clock=time.perf_counter`` when real
-latencies matter (benchmarks, serving).
+latencies matter (benchmarks, serving), or ``clock=profiler_clock`` to
+stamp spans on the clock of ``jax.profiler``'s device trace.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
+import threading
+import time
 from typing import Callable, Mapping
 
 #: Keys every exported Chrome trace event carries (the validator and the
 #: CI obs leg check these).
 CHROME_REQUIRED_KEYS = ("name", "ph", "ts", "pid", "tid")
+
+
+def profiler_clock() -> float:
+    """Seconds on the wall clock that ``jax.profiler`` stamps its trace
+    with.  Less the trace's ``profile_start_time``, a span on this clock
+    lines up with the device's ``XLA Ops`` events."""
+    return time.time_ns() * 1e-9
 
 
 class _NullSpan:
@@ -84,7 +100,7 @@ class _Span:
     """Live span handle: context manager recording a complete event."""
 
     __slots__ = ("_tracer", "name", "worker", "stream", "cat", "args",
-                 "_start")
+                 "_start", "id", "parent")
 
     def __init__(self, tracer, name, worker, stream, cat, args):
         self._tracer = tracer
@@ -94,22 +110,30 @@ class _Span:
         self.cat = cat
         self.args = args
         self._start = 0.0
+        self.id = None
+        self.parent = None
 
     def add(self, **args) -> None:
         """Attach key/value payload to the span (shows in Perfetto args)."""
         self.args.update(args)
 
     def __enter__(self):
+        open_spans = self._tracer._open_spans()
+        self.parent = open_spans[-1].id if open_spans else None
+        self.id = self._tracer.new_id()
+        open_spans.append(self)
         self._start = self._tracer.now()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         end = self._tracer.now()
+        self._tracer._open_spans().remove(self)
         if exc_type is not None:
             self.args.setdefault("error", exc_type.__name__)
         self._tracer.complete(
             self.name, self._start, end - self._start, worker=self.worker,
             stream=self.stream, cat=self.cat, args=self.args,
+            span_id=self.id, parent=self.parent,
         )
         return False
 
@@ -123,8 +147,14 @@ class Tracer:
     def __init__(self, clock: Callable[[], float] | None = None):
         self._clock = clock
         self._tick = 0
+        self._ids = itertools.count(1)
+        self._local = threading.local()  # per thread: the open spans
         # Raw events: ts/dur in SECONDS (converted to µs on export).
         self.events: list[dict] = []
+
+    @property
+    def clock(self) -> Callable[[], float] | None:
+        return self._clock
 
     # -- time ----------------------------------------------------------------
 
@@ -133,6 +163,24 @@ class Tracer:
             return float(self._clock())
         self._tick += 1
         return self._tick * 1e-6
+
+    # -- span tree --------------------------------------------------------------
+
+    def new_id(self) -> int:
+        """A fresh id, unique within this tracer (spans draw theirs here;
+        callers may too, to tie several spans together)."""
+        return next(self._ids)
+
+    def _open_spans(self) -> list[_Span]:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    def current(self) -> int | None:
+        """Id of the innermost span open on the calling thread."""
+        stack = self._open_spans()
+        return stack[-1].id if stack else None
 
     # -- recording ------------------------------------------------------------
 
@@ -143,15 +191,20 @@ class Tracer:
 
     def complete(self, name: str, ts: float, dur: float, *,
                  worker: int = 0, stream: str = "main", cat: str = "",
-                 args: Mapping | None = None) -> None:
+                 args: Mapping | None = None, span_id: int | None = None,
+                 parent: int | None = None) -> None:
         """Record a closed interval at an explicit timestamp (the
-        simulator's path — its event loop knows start and duration)."""
-        self.events.append({
+        simulator's path — its event loop knows start and duration).
+        With ``span_id`` the event joins the span tree under ``parent``."""
+        ev = {
             "name": str(name), "ph": "X", "ts": float(ts),
             "dur": max(0.0, float(dur)), "pid": int(worker),
             "stream": str(stream), "cat": str(cat),
             "args": dict(args or {}),
-        })
+        }
+        if span_id is not None:
+            ev["id"], ev["parent"] = span_id, parent
+        self.events.append(ev)
 
     def instant(self, name: str, *, ts: float | None = None, worker: int = 0,
                 stream: str = "main", cat: str = "",
@@ -203,7 +256,10 @@ class Tracer:
                 ev["dur"] = round(e["dur"] * 1e6, 3)
             if e["ph"] == "i":
                 ev["s"] = "t"  # thread-scoped instant
-            if e["args"]:
+            if "id" in e:
+                ev["args"] = {**e["args"], "id": e["id"],
+                              "parent": e["parent"]}
+            elif e["args"]:
                 ev["args"] = e["args"]
             body.append((ev["ts"], ev["pid"], ev["tid"], seq, ev))
         body.sort(key=lambda t: t[:4])
@@ -251,4 +307,5 @@ class Tracer:
 
 __all__ = [
     "CHROME_REQUIRED_KEYS", "NULL_TRACER", "NullTracer", "Tracer",
+    "profiler_clock",
 ]

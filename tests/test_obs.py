@@ -14,6 +14,7 @@ The load-bearing properties:
 """
 
 import json
+import time
 
 import numpy as np
 import pytest
@@ -212,6 +213,102 @@ class TestTracer:
         assert j1 == j2
         assert validate_chrome_trace(json.loads(j1)) == []
 
+    def test_spans_record_ids_and_their_parents(self):
+        tr = Tracer()
+        with tr.span("root"):
+            with tr.span("child"):
+                with tr.span("grandchild"):
+                    pass
+            with tr.span("sibling"):
+                assert tr.current() is not None
+        assert tr.current() is None
+        tr.complete("sim", 0.0, 1e-3)
+        tr.instant("mark", ts=0.0)
+        ev = {e["name"]: e for e in tr.events}
+        assert ev["root"]["parent"] is None
+        assert ev["child"]["parent"] == ev["root"]["id"]
+        assert ev["sibling"]["parent"] == ev["root"]["id"]
+        assert ev["grandchild"]["parent"] == ev["child"]["id"]
+        ids = [ev[n]["id"] for n in ("root", "child", "grandchild",
+                                     "sibling")]
+        assert len(set(ids)) == 4
+        # complete() and instant() keep their old shape: no tree fields
+        assert "id" not in ev["sim"] and "id" not in ev["mark"]
+        chrome = {e["name"]: e for e in tr.to_chrome()["traceEvents"]}
+        assert chrome["child"]["args"] == {"id": ev["child"]["id"],
+                                           "parent": ev["root"]["id"]}
+        assert "args" not in chrome["sim"]
+
+    def test_span_parents_are_per_thread(self):
+        """Threads opening spans at once on one tracer: every span's parent
+        is the span its own thread had open, and no id repeats."""
+        import sys
+        import threading
+
+        tr = Tracer()
+        threads, rounds = 16, 200
+
+        def work(t):
+            for r in range(rounds):
+                with tr.span(f"outer:{t}"):
+                    with tr.span(f"inner:{t}"):
+                        pass
+
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-6)
+        try:
+            with tr.span("main"):
+                pool = [threading.Thread(target=work, args=(t,))
+                        for t in range(threads)]
+                for th in pool:
+                    th.start()
+                for th in pool:
+                    th.join(timeout=60)
+                assert not any(th.is_alive() for th in pool)
+        finally:
+            sys.setswitchinterval(interval)
+        by_id = {e["id"]: e for e in tr.events}
+        assert len(by_id) == len(tr.events) == 2 * threads * rounds + 1
+        for e in tr.events:
+            kind, _, t = e["name"].partition(":")
+            if kind == "inner":
+                assert by_id[e["parent"]]["name"] == f"outer:{t}"
+            else:  # the main thread's span is no other thread's parent
+                assert e["parent"] is None
+
+    def test_profiler_clock_spans_land_in_the_device_trace(self, tmp_path):
+        """A span on profiler_clock around a jitted call, moved by the
+        trace's profile_start_time, lies inside the trace's window and
+        around the call's XLA op events."""
+        import jax
+        import jax.numpy as jnp
+
+        from repro.obs import profiler_clock
+
+        f = jax.jit(lambda x: jnp.sin(x) @ x)
+        x = jnp.ones((256, 256))
+        f(x).block_until_ready()
+        tr = Tracer(clock=profiler_clock)
+        with jax.profiler.trace(str(tmp_path)):
+            with tr.span("call"):
+                f(x).block_until_ready()
+        (path,) = tmp_path.glob("**/*.xplane.pb")
+        data = jax.profiler.ProfileData.from_file(str(path))
+        stats = {p.name: dict(p.stats) for p in data.planes}
+        start_ns = stats["Task Environment"]["profile_start_time"]
+        stop_ns = stats["Task Environment"]["profile_stop_time"]
+        (span,) = tr.events
+        lo = span["ts"] * 1e9 - start_ns
+        hi = lo + span["dur"] * 1e9
+        assert 0 <= lo < hi <= stop_ns - start_ns
+        ops = [(e.start_ns, e.start_ns + e.duration_ns)
+               for p in data.planes for line in p.lines
+               for e in line.events
+               if e.name.startswith(("dot_general", "wrapped_sine"))]
+        assert ops
+        slack = 1e3  # ns: a float64 of epoch seconds keeps ~0.24 µs
+        assert all(lo - slack <= s and e <= hi + slack for s, e in ops)
+
     def test_text_timeline_renders(self):
         lp = stencil_plan()
         tr = Tracer()
@@ -219,6 +316,72 @@ class TestTracer:
         txt = tr.text_timeline()
         assert "lanes" in txt.splitlines()[0]
         assert any("compute" in line for line in txt.splitlines())
+
+
+class TestCompileWatch:
+    """The routing of JAX's compile events, fed synthetic events through
+    the same callbacks ``jax.monitoring`` calls."""
+
+    TRACE = "/jax/core/compile/jaxpr_trace_duration"
+    LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+    COMPILE = "/jax/core/compile/backend_compile_duration"
+
+    def test_counts_stages_and_unions_nested_intervals(self):
+        from repro.obs import compiles
+
+        with compiles.CompileWatch(NULL_TRACER) as w:
+            compiles._on_time_span(self.TRACE, 10.0, 11.0, fun_name="f")
+            compiles._on_time_span(self.TRACE, 10.2, 10.4, fun_name="g")
+            compiles._on_time_span(self.LOWER, 11.0, 11.5, fun_name="f")
+            compiles._on_time_span(self.COMPILE, 12.0, 12.25, fun_name="f")
+            compiles._on_event(compiles.CACHE_HIT)
+            compiles._on_time_span("/jax/other", 0.0, 100.0)
+            compiles._on_event("/jax/other")
+        assert (w.programs, w.traces, w.cache_loads) == (1, 2, 1)
+        # nested (10.2–10.4 inside 10–11) and touching intervals count once
+        assert w.compile_s == pytest.approx(1.75)
+
+    def test_events_outside_a_watch_or_on_another_thread_are_ignored(self):
+        import threading
+
+        from repro.obs import compiles
+
+        compiles._on_time_span(self.COMPILE, 0.0, 1.0, fun_name="f")
+        with compiles.CompileWatch(NULL_TRACER) as w:
+            t = threading.Thread(target=compiles._on_time_span,
+                                 args=(self.COMPILE, 0.0, 1.0))
+            t.start()
+            t.join(timeout=10)
+            assert not t.is_alive()
+        compiles._on_time_span(self.COMPILE, 0.0, 1.0, fun_name="f")
+        assert w.programs == 0 and w.compile_s == 0.0
+
+    def test_child_spans_on_the_profiler_clock_keep_jaxs_times(self):
+        from repro.obs import compiles, profiler_clock
+
+        tr = Tracer(clock=profiler_clock)
+        now = profiler_clock()
+        with tr.span("execute:k") as parent:
+            with compiles.CompileWatch(tr, launch=7):
+                compiles._on_time_span(self.LOWER, now - 0.5, now - 0.2,
+                                       fun_name="f")
+        (child,) = [e for e in tr.events if e["name"] == "jax:lower"]
+        assert child["ts"] == now - 0.5
+        assert child["dur"] == pytest.approx(0.3)
+        assert child["parent"] == parent.id
+        assert child["args"] == {"fun": "f", "launch": 7}
+
+    def test_child_spans_on_another_clock_end_when_reported(self):
+        from repro.obs import compiles
+
+        tr = Tracer()  # logical clock: 1 µs per read
+        with compiles.CompileWatch(tr):
+            compiles._on_time_span(self.COMPILE, 100.0, 100.002,
+                                   fun_name="f")
+        (child,) = tr.events
+        assert child["dur"] == pytest.approx(0.002)
+        assert child["ts"] + child["dur"] == pytest.approx(tr.now() - 1e-6)
+        assert child["parent"] is None and child["args"] == {"fun": "f"}
 
 
 # ---------------------------------------------------------------------------
@@ -336,6 +499,150 @@ class TestRuntimeIntegration:
         assert reg.snapshot()["launch.count{kernel=scale}"] == 1
         names = [e["name"] for e in tr.events]
         assert "plan:scale" in names and "launch:scale" in names
+
+    def test_launch_spans_share_one_launch_id(self):
+        import jax
+        import jax.numpy as jnp
+
+        from repro.core import Context, KernelDef
+
+        tr = Tracer(clock=time.perf_counter)
+        ctx = Context(tracer=tr, registry=MetricsRegistry())
+        fresh = jax.jit(lambda x: x * 3.0 + 1.0)  # new: traced and compiled
+        k = KernelDef.define(
+            "affine", lambda views, info: {"y": fresh(views["x"])},
+            "global i => read x[i], write y[i]",
+        )
+        x = ctx.array(jnp.ones(16), name="x")
+        y = ctx.zeros((16,), name="y")
+        ctx.launch(k, grid=(16,), args={"x": x, "y": y})
+        ev = {}
+        for e in tr.events:
+            ev.setdefault(e["name"], []).append(e)
+        (plan,), (launch,), (execute,) = (ev["plan:affine"],
+                                          ev["launch:affine"],
+                                          ev["execute:affine"])
+        lid = launch["args"]["launch"]
+        assert plan["args"]["launch"] == execute["args"]["launch"] == lid
+        assert execute["parent"] == launch["id"]
+        jax_spans = [e for e in tr.events if e["name"].startswith("jax:")]
+        assert {e["name"] for e in jax_spans} >= {"jax:trace", "jax:lower",
+                                                  "jax:compile"}
+        for e in jax_spans:
+            assert e["args"]["launch"] == lid
+            assert e["parent"] == execute["id"]
+            assert e["stream"] == "driver" and e["args"]["fun"]
+            # a child lies inside its parent
+            assert execute["ts"] <= e["ts"]
+            assert e["ts"] + e["dur"] <= execute["ts"] + execute["dur"]
+        a = launch["args"]
+        assert a["programs"] == len(ev["jax:compile"]) >= 1
+        assert a["traces"] == len(ev["jax:trace"])
+        assert 0 < a["compile_s"] <= launch["dur"]
+        # only this launch's children: none outside the launch id
+        assert all(e["args"].get("launch") == lid for e in tr.events)
+
+    def test_null_tracer_launch_records_no_span_but_counts(self,
+                                                           monkeypatch):
+        import jax.numpy as jnp
+
+        from repro.core import Context, KernelDef
+        from repro.obs import trace as trace_mod
+
+        def boom(*a, **k):
+            raise AssertionError("a span or event was built")
+
+        monkeypatch.setattr(trace_mod._Span, "__init__", boom)
+        monkeypatch.setattr(trace_mod.Tracer, "complete", boom)
+        reg = MetricsRegistry()
+        ctx = Context(registry=reg)
+        assert ctx.tracer is NULL_TRACER
+        k = KernelDef.define(
+            "halve", lambda views, info: {"y": views["x"] * 0.5},
+            "global i => read x[i], write y[i]",
+        )
+        x = ctx.array(jnp.ones(8), name="x")
+        y = ctx.zeros((8,), name="y")
+        ctx.launch(k, grid=(8,), args={"x": x, "y": y})
+        snap = reg.snapshot()
+        assert snap["launch.count{kernel=halve}"] == 1
+        assert "launch.programs{kernel=halve}" in snap
+        assert "launch.compile_s{kernel=halve}" in snap
+
+    def test_repeated_single_device_launch_compiles_nothing(self):
+        import jax.numpy as jnp
+
+        from repro.core import Context, KernelDef
+
+        reg = MetricsRegistry()
+        ctx = Context(tracer=Tracer(clock=time.perf_counter), registry=reg)
+        k = KernelDef.define(
+            "shift", lambda views, info: {"y": views["x"] + 7.0},
+            "global i => read x[i], write y[i]",
+        )
+        x = ctx.array(jnp.ones(24), name="x")
+        y = ctx.zeros((24,), name="y")
+        ctx.launch(k, grid=(24,), args={"x": x, "y": y})
+        first = reg.snapshot()
+        for _ in range(3):
+            ctx.launch(k, grid=(24,), args={"x": x, "y": y})
+        delta = MetricsRegistry.diff(reg.snapshot(), first)
+        assert delta["launch.count{kernel=shift}"] == 3
+        assert delta["launch.programs{kernel=shift}"] == 0
+        assert delta["launch.compile_s{kernel=shift}"] == 0
+        spans = [e for e in ctx.tracer.events
+                 if e["name"] == "launch:shift"][1:]
+        assert [e["args"]["programs"] for e in spans] == [0, 0, 0]
+
+    def test_mesh_launch_counts_what_an_outside_listener_sees(self):
+        """On 4 virtual devices, a mesh launch's ``programs`` equals the
+        backend compiles an independent listener saw during it (equality,
+        not a number: a launch that compiles nothing must read 0)."""
+        from _subproc import run_with_devices
+
+        out = run_with_devices("""
+import json, time
+import jax, numpy as np
+from repro.core import *
+from repro.obs import MetricsRegistry, Tracer
+
+seen = []
+jax.monitoring.register_event_duration_secs_listener(
+    lambda ev, d, **kw: seen.append(ev.endswith("backend_compile_duration")))
+mesh = jax.make_mesh((4,), ("data",))
+reg = MetricsRegistry()
+tr = Tracer(clock=time.perf_counter)
+ctx = Context(mesh=mesh, tracer=tr, registry=reg)
+k = KernelDef.define(
+    "stencil", lambda v, i: {"output": (v["input"][:-2] + v["input"][2:]) / 2},
+    "global i => read input[i-1:i+1], write output[i]")
+n = 256
+inp = ctx.array(np.arange(n, dtype=np.float32), dist=StencilDist(n // 4, 1),
+                name="input")
+out = ctx.zeros((n,), dist=BlockDist(n // 4), name="output")
+rows = []
+for _ in range(2):
+    before = sum(seen)
+    ctx.launch(k, grid=(n,), args={"input": inp, "output": out})
+    rows.append(sum(seen) - before)
+launches = [e for e in tr.events if e["name"] == "launch:stencil"]
+print(json.dumps({"seen": rows, "launches": launches, "events": tr.events,
+                  "programs": reg.snapshot()["launch.programs{kernel=stencil}"]}))
+""", n_devices=4)
+        got = json.loads(out.strip().splitlines()[-1])
+        launches = got["launches"]
+        assert [e["args"]["programs"] for e in launches] == got["seen"]
+        assert got["programs"] == sum(got["seen"])
+        for launch in launches:
+            assert launch["args"]["devices"] == 4
+            assert 0 <= launch["args"]["compile_s"] <= launch["dur"]
+            lid = launch["args"]["launch"]
+            mine = [e for e in got["events"] if e["args"]["launch"] == lid]
+            assert {e["name"] for e in mine} >= {"plan:stencil",
+                                                 "launch:stencil",
+                                                 "execute:stencil"}
+            compiles = [e for e in mine if e["name"] == "jax:compile"]
+            assert len(compiles) == launch["args"]["programs"]
 
     def test_serve_engine_metrics(self):
         import jax
