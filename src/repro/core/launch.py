@@ -13,9 +13,12 @@ consistency via chunk-conflict edges), and dispatches execution:
 
 * **single device** — the kernel body runs on full-array views (the planner
   still runs, so plans/DAGs are inspectable and the simulator can cost them);
-* **mesh** — the launch lowers to one ``shard_map``: each device executes its
-  superblock; the planner's per-argument :class:`CommPattern` decides the
-  collective that materializes each argument's access region:
+* **mesh** — the launch lowers to one jitted ``shard_map``, built on the
+  first launch of its signature (kernel, grid, argument shapes, dtypes and
+  specs, planned patterns, scalars) and reused from the context's cache
+  after that: each device executes its superblock; the planner's
+  per-argument :class:`CommPattern` decides the collective that
+  materializes each argument's access region:
 
     LOCAL       shard passed straight through (no communication)
     REPLICATED  full array everywhere (storage is replicated)
@@ -30,8 +33,8 @@ local-coordinate views handed to the body.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
-import functools
 from typing import Any, Callable, Mapping, Sequence
 
 import jax
@@ -66,7 +69,9 @@ class KernelDef:
     returns the local partial over the full output region).
 
     The body may be a plain jnp function or a Pallas ``ops`` wrapper — both
-    are traced inside the launch's jitted program.
+    are traced inside the launch's jitted program on a mesh, and run as they
+    are on one device.  A ``KernelDef`` is hashed and compared by its fields,
+    so keeping one and launching it again reuses the mesh program.
     """
 
     name: str
@@ -149,6 +154,8 @@ class Context:
         # launches with chunk-conflict edges (sequential consistency).
         self.plan = ExecutionPlan(launch_name="driver")
         self._array_counter = 0
+        # Jitted mesh programs by launch signature (see ``_execute_mesh``).
+        self._mesh_programs: collections.OrderedDict = collections.OrderedDict()
 
     # -- array factory (paper: context.ones / zeros) ---------------------------
 
@@ -243,7 +250,7 @@ class Context:
             with CompileWatch(tracer, launch=lid) as seen, (
                     tracer.span(f"execute:{kernel.name}", stream="driver",
                                 cat="compute", launch=lid)
-                    if traced else _NULL_SPAN):
+                    if traced else _NULL_SPAN) as execute_span:
                 if self.mesh is None or self.mesh.size == 1:
                     outputs = self._with_recovery(
                         kernel, lambda: self._execute_single(kernel, grid,
@@ -252,11 +259,13 @@ class Context:
                     in_specs = {n: P() for n in args}
                     out_specs = {n: P() for n in outputs}
                 else:
-                    outputs, in_specs, out_specs = self._with_recovery(
+                    outputs, in_specs, out_specs, cached = self._with_recovery(
                         kernel, lambda: self._execute_mesh(kernel, grid, args,
                                                            scalars, plan,
                                                            work_dist)
                     )
+                    if traced:
+                        execute_span.add(cached=cached)
             compile_s = seen.compile_s
             registry.counter("launch.programs").labels(
                 kernel=kernel.name).inc(seen.programs)
@@ -361,11 +370,29 @@ class Context:
         scalars: dict[str, Any],
         plan: LaunchPlan,
         work_dist: WorkDistribution,
-    ) -> tuple[dict[str, jax.Array], dict[str, P], dict[str, P]]:
+    ) -> tuple[dict[str, jax.Array], dict[str, P], dict[str, P], bool]:
+        """Run one launch as a jitted ``shard_map``, reusing a cached one.
+
+        The program is built once per launch signature and kept on this
+        context; the key is what the launch's input shows: the
+        :class:`KernelDef`, the grid and split axis, the argument names in
+        order with each one's shape and dtype, the in/out partition specs,
+        each argument's planned ``(pattern, mode, reduce_op, halo_width)``
+        and the scalars.  A hashable scalar is keyed by type and value and
+        baked into the program, so a scalar that changes on every launch
+        should be a ``jax.Array``, which is passed to the program as a
+        replicated argument; any other scalar makes the launch build its
+        program without caching it.  The program closes over names, specs
+        and patterns only, never over a launch's arrays.  A caller that
+        builds a new ``KernelDef`` for every launch misses every time and
+        pays one compile of the whole program per launch; the least recently
+        used of more than ``_MESH_PROGRAMS`` programs is dropped.
+
+        Returns the written values, the in/out specs and whether the
+        program came from the cache.
+        """
         mesh = self.mesh
         assert mesh is not None
-        axes = self.mesh_axes
-        work_axes = axes  # grid axis 0 is split over all mesh axes jointly
         ann = kernel.annotation
 
         # Which grid axis does the work distribution split?  (Our work
@@ -383,7 +410,7 @@ class Context:
                 in_specs[name] = P()
             else:
                 in_specs[name] = arr.partition_spec()
-        written = [s.array for s in ann.stmts if s.writes]
+        written = tuple(s.array for s in ann.stmts if s.writes)
         for name in written:
             ap = patterns[name]
             if ap.pattern is CommPattern.REDUCE or ap.mode == MODE_REDUCE:
@@ -393,80 +420,138 @@ class Context:
             else:
                 out_specs[name] = args[name].partition_spec()
 
-        grid_sizes = grid
-        n_shards = mesh.size
+        names = tuple(args)
+        arg_plans = tuple(
+            (patterns[n].pattern, patterns[n].mode, patterns[n].reduce_op,
+             patterns[n].halo_width) for n in names)
+        replicated = NamedSharding(mesh, P())
+        dynamic = {k: jax.device_put(v, replicated) for k, v in scalars.items()
+                   if isinstance(v, jax.Array)}
+        static = tuple(sorted((k, type(v), v) for k, v in scalars.items()
+                              if k not in dynamic))
+        key = (kernel, grid, split_axis, names,
+               tuple((a.shape, a.dtype) for a in args.values()),
+               tuple(in_specs.items()), tuple(out_specs.items()), arg_plans,
+               static, tuple(dynamic))
+        try:
+            fn = self._mesh_programs.get(key)
+        except TypeError:  # an unhashable scalar: build, run, don't keep
+            key = fn = None
+        cached = fn is not None
+        if cached:
+            self._mesh_programs.move_to_end(key)
+        else:
+            fn = _mesh_program(kernel, mesh, self.mesh_axes, grid, split_axis,
+                               names, arg_plans, written, in_specs, out_specs,
+                               {k: v for k, _, v in static}, tuple(dynamic))
+        out_vals = fn(*[a.value for a in args.values()], *dynamic.values())
+        if key is not None and not cached:
+            self._mesh_programs[key] = fn
+            if len(self._mesh_programs) > _MESH_PROGRAMS:
+                self._mesh_programs.popitem(last=False)
+        self.registry.counter("launch.mesh_cache").labels(
+            kernel=kernel.name, result="hit" if cached else "miss").inc()
+        return dict(zip(written, out_vals)), in_specs, out_specs, cached
 
-        def shard_body(*vals):
-            views: dict[str, jax.Array] = {}
-            named = dict(zip(args.keys(), vals))
-            # Device/superblock identity inside shard_map.
-            idx = jax.lax.axis_index(axes[0])
-            for i, ax in enumerate(axes[1:]):
-                idx = idx * mesh.shape[ax] + jax.lax.axis_index(ax)
-            sb_threads = grid_sizes[split_axis] // n_shards
-            offset = [0] * len(grid_sizes)
-            offset[split_axis] = idx * sb_threads
-            local_shape = list(grid_sizes)
-            local_shape[split_axis] = sb_threads
 
-            for name, val in named.items():
-                ap = patterns[name]
-                stmt = ann.stmt_for(name)
-                if ap.pattern is CommPattern.LOCAL or ap.pattern is CommPattern.REPLICATED:
-                    views[name] = val
-                elif ap.pattern is CommPattern.GATHER and stmt.reads:
-                    full = val
-                    sharded_dims = [
-                        d for d, s in enumerate(in_specs[name])
-                        if s is not None
-                    ] if len(in_specs[name]) else []
-                    for d in sharded_dims:
-                        spec_axes = in_specs[name][d]
-                        spec_axes = (spec_axes,) if isinstance(spec_axes, str) else spec_axes
-                        for a in spec_axes:
-                            full = jax.lax.all_gather(full, a, axis=d, tiled=True)
-                    views[name] = full
-                elif ap.pattern is CommPattern.HALO:
-                    views[name] = _halo_exchange(
-                        val, ap.halo_width or (1,), axes, mesh
-                    )
-                elif ap.pattern is CommPattern.REDUCE:
-                    views[name] = val  # partial buffer; body overwrites
-                else:  # SCATTER etc.: gather fallback (correct, slower)
-                    full = val
-                    for d, s in enumerate(in_specs[name]):
-                        if s is None:
-                            continue
-                        for a in ((s,) if isinstance(s, str) else s):
-                            full = jax.lax.all_gather(full, a, axis=d, tiled=True)
-                    views[name] = full
+#: Most jitted mesh programs one :class:`Context` keeps (least recently used
+#: dropped first): a bound for callers that make a new kernel every launch.
+_MESH_PROGRAMS = 64
 
-            info = SuperblockInfo(
-                grid=grid_sizes,
-                thread_offset=tuple(offset),
-                local_shape=tuple(local_shape),
-                device_index=idx,
-                scalars=scalars,
-            )
-            outs = dict(kernel.body(views, info))
-            final = []
-            for name in written:
-                ap = patterns[name]
-                o = outs[name]
-                if ap.pattern is CommPattern.REDUCE or ap.mode == MODE_REDUCE:
-                    o = collective_reduce(ap.reduce_op or "+", o, axes)
-                final.append(o)
-            return tuple(final)
 
-        fn = jax.shard_map(
-            shard_body,
-            mesh=mesh,
-            in_specs=tuple(in_specs[n] for n in args),
-            out_specs=tuple(out_specs[n] for n in written),
-            check_vma=False,
+def _mesh_program(
+    kernel: KernelDef,
+    mesh: Mesh,
+    axes: tuple[str, ...],
+    grid: tuple[int, ...],
+    split_axis: int,
+    names: tuple[str, ...],
+    arg_plans: tuple[tuple, ...],
+    written: tuple[str, ...],
+    in_specs: dict[str, P],
+    out_specs: dict[str, P],
+    static_scalars: dict[str, Any],
+    dynamic_scalars: tuple[str, ...],
+) -> Callable[..., tuple[jax.Array, ...]]:
+    """One launch signature's program: ``jax.jit`` of a ``shard_map`` whose
+    body gives each device its superblock's views, runs the kernel body and
+    combines ``reduce`` outputs.  It takes the arguments' values in
+    ``names`` order, then the ``dynamic_scalars``, and returns the written
+    values in ``written`` order."""
+    ann = kernel.annotation
+    plans = dict(zip(names, arg_plans))
+    n_shards = mesh.size
+
+    def shard_body(*vals):
+        views: dict[str, jax.Array] = {}
+        named = dict(zip(names, vals))
+        scalars = dict(static_scalars)
+        scalars.update(zip(dynamic_scalars, vals[len(names):]))
+        # Device/superblock identity inside shard_map.
+        idx = jax.lax.axis_index(axes[0])
+        for ax in axes[1:]:
+            idx = idx * mesh.shape[ax] + jax.lax.axis_index(ax)
+        sb_threads = grid[split_axis] // n_shards
+        offset = [0] * len(grid)
+        offset[split_axis] = idx * sb_threads
+        local_shape = list(grid)
+        local_shape[split_axis] = sb_threads
+
+        for name, val in named.items():
+            pattern, _, _, halo_width = plans[name]
+            stmt = ann.stmt_for(name)
+            if pattern is CommPattern.LOCAL or pattern is CommPattern.REPLICATED:
+                views[name] = val
+            elif pattern is CommPattern.GATHER and stmt.reads:
+                full = val
+                sharded_dims = [
+                    d for d, s in enumerate(in_specs[name])
+                    if s is not None
+                ] if len(in_specs[name]) else []
+                for d in sharded_dims:
+                    spec_axes = in_specs[name][d]
+                    spec_axes = (spec_axes,) if isinstance(spec_axes, str) else spec_axes
+                    for a in spec_axes:
+                        full = jax.lax.all_gather(full, a, axis=d, tiled=True)
+                views[name] = full
+            elif pattern is CommPattern.HALO:
+                views[name] = _halo_exchange(val, halo_width or (1,), axes, mesh)
+            elif pattern is CommPattern.REDUCE:
+                views[name] = val  # partial buffer; body overwrites
+            else:  # SCATTER etc.: gather fallback (correct, slower)
+                full = val
+                for d, s in enumerate(in_specs[name]):
+                    if s is None:
+                        continue
+                    for a in ((s,) if isinstance(s, str) else s):
+                        full = jax.lax.all_gather(full, a, axis=d, tiled=True)
+                views[name] = full
+
+        info = SuperblockInfo(
+            grid=grid,
+            thread_offset=tuple(offset),
+            local_shape=tuple(local_shape),
+            device_index=idx,
+            scalars=scalars,
         )
-        out_vals = fn(*[a.value for a in args.values()])
-        return dict(zip(written, out_vals)), in_specs, out_specs
+        outs = dict(kernel.body(views, info))
+        final = []
+        for name in written:
+            pattern, mode, reduce_op, _ = plans[name]
+            o = outs[name]
+            if pattern is CommPattern.REDUCE or mode == MODE_REDUCE:
+                o = collective_reduce(reduce_op or "+", o, axes)
+            final.append(o)
+        return tuple(final)
+
+    return jax.jit(jax.shard_map(
+        shard_body,
+        mesh=mesh,
+        in_specs=(tuple(in_specs[n] for n in names)
+                  + (P(),) * len(dynamic_scalars)),
+        out_specs=tuple(out_specs[n] for n in written),
+        check_vma=False,
+    ))
 
 
 def _halo_exchange(

@@ -594,6 +594,51 @@ class TestRuntimeIntegration:
                  if e["name"] == "launch:shift"][1:]
         assert [e["args"]["programs"] for e in spans] == [0, 0, 0]
 
+    def test_repeated_mesh_launch_compiles_nothing(self):
+        """On 4 virtual devices the first mesh launch builds and compiles
+        its jitted ``shard_map``; the next three reuse it and compile
+        nothing."""
+        from _subproc import run_with_devices
+
+        out = run_with_devices("""
+import json, time
+import jax, numpy as np
+from repro.core import *
+from repro.obs import MetricsRegistry, Tracer
+
+reg = MetricsRegistry()
+tr = Tracer(clock=time.perf_counter)
+ctx = Context(mesh=jax.make_mesh((4,), ("data",)), tracer=tr, registry=reg)
+k = KernelDef.define(
+    "stencil", lambda v, i: {"output": (v["input"][:-2] + v["input"][2:]) / 2},
+    "global i => read input[i-1:i+1], write output[i]")
+n = 256
+inp = ctx.array(np.arange(n, dtype=np.float32), dist=StencilDist(n // 4, 1),
+                name="input")
+out = ctx.zeros((n,), dist=BlockDist(n // 4), name="output")
+ctx.launch(k, grid=(n,), args={"input": inp, "output": out})
+first = reg.snapshot()
+for _ in range(3):
+    ctx.launch(k, grid=(n,), args={"input": inp, "output": out})
+print(json.dumps({"first": first, "delta": MetricsRegistry.diff(reg.snapshot(), first),
+                  "events": tr.events}))
+""", n_devices=4)
+        got = json.loads(out.strip().splitlines()[-1])
+        first, delta = got["first"], got["delta"]
+        assert first["launch.programs{kernel=stencil}"] >= 1
+        assert first["launch.mesh_cache{kernel=stencil,result=miss}"] == 1
+        assert delta["launch.count{kernel=stencil}"] == 3
+        assert delta["launch.programs{kernel=stencil}"] == 0
+        assert delta["launch.compile_s{kernel=stencil}"] == 0
+        assert delta["launch.mesh_cache{kernel=stencil,result=hit}"] == 3
+        assert delta.get("launch.mesh_cache{kernel=stencil,result=miss}",
+                         0) == 0
+        spans = {name: [e["args"] for e in got["events"] if e["name"] == name]
+                 for name in ("launch:stencil", "execute:stencil")}
+        assert [a["programs"] for a in spans["launch:stencil"]][1:] == [0] * 3
+        assert [a["cached"] for a in spans["execute:stencil"]] == [
+            False, True, True, True]
+
     def test_mesh_launch_counts_what_an_outside_listener_sees(self):
         """On 4 virtual devices, a mesh launch's ``programs`` equals the
         backend compiles an independent listener saw during it (equality,
