@@ -6,6 +6,10 @@ layout is a ``NamedSharding`` derived from the distribution's partition spec;
 on a single device it is an ordinary array, and the chunk structure exists
 only in planner metadata (exactly the paper's "distributions affect
 performance, not correctness").
+
+An array's ``tier`` says where it lives: ``DEVICE`` (a ``jax.Array``) or
+``HOST`` (a numpy array larger than the device can take, paper §3.4), whose
+launches stream the regions each superblock reads through the device.
 """
 
 from __future__ import annotations
@@ -24,6 +28,10 @@ from .ndrange import Region
 from .planner import ArrayMeta
 
 
+#: Where an array's value lives.
+DEVICE, HOST = "device", "host"
+
+
 def _dtype_size(dtype: Any) -> int:
     return jnp.dtype(dtype).itemsize
 
@@ -37,6 +45,7 @@ class DistributedArray:
     dist: Distribution
     mesh: Mesh | None = None
     mesh_axes: tuple[str, ...] = ()
+    tier: str = DEVICE  # HOST: ``value`` is a numpy array in host memory
 
     # -- metadata ---------------------------------------------------------------
 
@@ -101,18 +110,23 @@ def make_array(
     dist: Distribution,
     mesh: Mesh | None = None,
     mesh_axes: Sequence[str] = (),
+    tier: str = DEVICE,
 ) -> DistributedArray:
     """Place ``value`` according to ``dist``.  On a mesh, host data goes
     straight to its ``NamedSharding``: each device receives only its share,
-    never the whole array first."""
+    never the whole array first.  On the ``HOST`` tier the value stays a
+    numpy array where it is."""
     arr = DistributedArray(
         name=name,
         value=value,
         dist=dist,
         mesh=mesh,
         mesh_axes=tuple(mesh_axes),
+        tier=tier,
     )
-    if mesh is not None and mesh.size > 1:
+    if tier == HOST:
+        arr.value = np.asarray(value)
+    elif mesh is not None and mesh.size > 1:
         arr.value = jax.device_put(value, arr.sharding())
     else:
         arr.value = jnp.asarray(value)

@@ -13,6 +13,11 @@ consistency via chunk-conflict edges), and dispatches execution:
 
 * **single device** — the kernel body runs on full-array views (the planner
   still runs, so plans/DAGs are inspectable and the simulator can cost them);
+* **out of core** — a single-device launch with an argument on the host
+  tier (an array larger than the device can take, paper §3.4) runs the
+  plan's EXECUTE tasks in order, one superblock at a time: each task's read
+  region of the host array goes to the device while the body runs on the
+  previous one, and ``reduce`` outputs are combined on the device;
 * **mesh** — the launch lowers to one jitted ``shard_map``, built on the
   first launch of its signature (kernel, grid, argument shapes, dtypes and
   specs, planned patterns, scalars) and reused from the context's cache
@@ -34,7 +39,10 @@ local-coordinate views handed to the body.
 from __future__ import annotations
 
 import collections
+import concurrent.futures
 import dataclasses
+import functools
+import time
 from typing import Any, Callable, Mapping, Sequence
 
 import jax
@@ -47,15 +55,15 @@ from repro.obs.metrics import MetricsRegistry, default_registry
 from repro.obs.trace import _NULL_SPAN, NULL_TRACER
 
 from . import annotations as ann_mod
-from .annotations import Annotation, REDUCE as MODE_REDUCE
-from .dist_array import DistributedArray, full_array, make_array
+from .annotations import READ, Annotation, REDUCE as MODE_REDUCE
+from .dist_array import DEVICE, HOST, DistributedArray, full_array, make_array
 from .distributions import Distribution, ReplicatedDist
 from .faults import FaultInjector, RecoveryPolicy
 from .ndrange import Region
-from .plan_ir import CommPattern, ExecutionPlan, LaunchPlan
+from .plan_ir import CommPattern, ExecutionPlan, LaunchPlan, Task, TaskKind
 from .planner import ArrayMeta, Planner, Topology
-from .reductions import collective_reduce
-from .superblock import EvenWork, WorkDistribution
+from .reductions import collective_reduce, combine, identity_for
+from .superblock import BlockWork, EvenWork, WorkDistribution
 
 
 @dataclasses.dataclass(frozen=True)
@@ -124,8 +132,19 @@ class Context:
         tracer=None,
         registry: MetricsRegistry | None = None,
         plan_cache: bool = True,
+        device_budget: int | None = None,
     ):
         self.mesh = mesh
+        # Bytes the device may take, for devices that report no memory
+        # limit (the CPU); otherwise read from the device (``device_budget``).
+        # 0 keeps every numpy array on the host tier (``stream_kmeans``).
+        self._device_budget = device_budget
+        # The budget that sizes streamed superblocks, read once so every
+        # launch of this context streams chunks of the same shape.
+        self._stream_budget: int | None = None
+        # Host-tier chunks on the device, oldest first, each with the output
+        # that consumed it: at most two are kept (``_execute_streamed``).
+        self._streaming: collections.deque = collections.deque()
         # Observability: launches emit plan/launch/execute spans (and JAX's
         # jax:* compile stages) on the ``driver`` stream and count launches,
         # retries, recoveries, compiled programs and compile seconds on the
@@ -141,9 +160,13 @@ class Context:
         if mesh is not None:
             self.mesh_axes = tuple(mesh_axes or mesh.axis_names)
             num_devices = mesh.size
+            self._device = mesh.devices.flat[0]
         else:
             self.mesh_axes = tuple(mesh_axes or ())
             num_devices = 1
+            default = jax.config.jax_default_device
+            self._device = (jax.devices(default)[0] if isinstance(default, str)
+                            else default or jax.devices()[0])
         self.topology = Topology(num_devices, devices_per_node)
         # Plan caching (repeated launches skip re-planning) shares this
         # context's registry so hit/miss counters land with the launch ones.
@@ -172,19 +195,47 @@ class Context:
         self._array_counter += 1
         return f"{prefix}_{self._array_counter}"
 
+    @property
+    def device(self) -> jax.Device:
+        """The device of a one-device context: the mesh's, else JAX's
+        default device when the context was made."""
+        return self._device
+
+    def device_budget(self) -> int | None:
+        """Bytes the device can take now: the budget this context was given,
+        else :attr:`device`'s ``bytes_limit`` less its ``bytes_in_use``;
+        ``None`` where the device reports no limit."""
+        if self._device_budget is not None:
+            return self._device_budget
+        stats = self.device.memory_stats() or {}
+        if "bytes_limit" not in stats:
+            return None
+        return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+
     def array(
         self,
         value: jax.Array | np.ndarray,
         dist: Distribution | None = None,
         name: str | None = None,
     ) -> DistributedArray:
+        """Register ``value``.  On one device, a numpy array larger than
+        :meth:`device_budget` (its bytes in host memory; a narrow array's
+        device layout may take more) stays in host memory (the ``HOST``
+        tier) and its launches stream it; anything else is placed on the
+        device."""
         dist = dist or ReplicatedDist()
+        tier = DEVICE
+        if isinstance(value, np.ndarray) and self.num_devices == 1:
+            budget = self.device_budget()
+            if budget is not None and value.nbytes > budget:
+                tier = HOST
         return make_array(
             name or self._fresh_name("arr"),
             value,
             dist,
             mesh=self.mesh,
             mesh_axes=self.mesh_axes,
+            tier=tier,
         )
 
     def zeros(self, shape, dtype=jnp.float32, dist=None, name=None):
@@ -220,6 +271,10 @@ class Context:
         array (functional update — JAX arrays are immutable, so "writes"
         produce replacements; the Context rebinds names in its records)."""
         grid = tuple(int(g) for g in grid)
+        streamed = [n for n, a in args.items() if a.tier == HOST]
+        if streamed and work_dist is None:
+            work_dist = BlockWork(self._stream_rows(grid[work_axis], [
+                args[n] for n in streamed]), axis=work_axis)
         work_dist = work_dist or EvenWork(axis=work_axis)
         scalars = dict(scalars or {})
         arrays = {name: a.meta() for name, a in args.items()}
@@ -233,6 +288,7 @@ class Context:
         with (tracer.span(f"plan:{kernel.name}", stream="driver", cat="sched",
                           grid=list(grid), launch=lid)
               if traced else _NULL_SPAN):
+            first_task = len(self.plan.tasks)
             plan = self.planner.plan_launch(
                 kernel.name, kernel.annotation, grid, work_dist, arrays,
                 block_shape=block_shape, plan=self.plan,
@@ -252,10 +308,16 @@ class Context:
                                 cat="compute", launch=lid)
                     if traced else _NULL_SPAN) as execute_span:
                 if self.mesh is None or self.mesh.size == 1:
-                    outputs = self._with_recovery(
-                        kernel, lambda: self._execute_single(kernel, grid,
-                                                             args, scalars)
-                    )
+                    if streamed:
+                        tasks = [t for t in self.plan.tasks[first_task:]
+                                 if t.kind is TaskKind.EXECUTE]
+                        run = functools.partial(
+                            self._execute_streamed, kernel, grid, args,
+                            scalars, tasks, block_shape, lid)
+                    else:
+                        run = functools.partial(self._execute_single, kernel,
+                                                grid, args, scalars)
+                    outputs = self._with_recovery(kernel, run)
                     in_specs = {n: P() for n in args}
                     out_specs = {n: P() for n in outputs}
                 else:
@@ -360,6 +422,126 @@ class Context:
         # reduce() partials on one device are already the full reduction.
         return outs
 
+    # -- out-of-core execution -------------------------------------------------------
+
+    def _stream_rows(self, extent: int,
+                     streamed: Sequence[DistributedArray]) -> int:
+        """Superblock size, in steps of the work axis, of a launch that
+        streams ``streamed``: the largest power of two at which two
+        superblocks' host bytes take at most ``_STREAM_SHARE`` of the
+        budget, read at the context's first streamed launch.  The rest of
+        the budget is left for the device layout's padding of a chunk, the
+        body's temporaries and the arrays already on the device."""
+        if self._stream_budget is None:
+            self._stream_budget = self.device_budget() or 0
+        row_bytes = sum(a.nbytes for a in streamed) / max(extent, 1)
+        fit = int(self._stream_budget * _STREAM_SHARE / (2 * row_bytes))
+        rows = 1 << (max(fit, 1).bit_length() - 1)
+        return max(1, min(rows, extent))
+
+    def _execute_streamed(
+        self,
+        kernel: KernelDef,
+        grid: tuple[int, ...],
+        args: Mapping[str, DistributedArray],
+        scalars: dict[str, Any],
+        tasks: Sequence[Task],
+        block_shape: Sequence[int] | None,
+        lid: int | None,
+    ) -> dict[str, jax.Array]:
+        """Run the plan's EXECUTE ``tasks`` in order over the host-tier
+        arguments (paper §3.4).  Each task's read region of a host array
+        goes to the device with ``jax.device_put``, in pieces put
+        concurrently (``_put_pieces``); the transfer for task i+1 is
+        enqueued before the body runs on task i, after the output of task
+        i-1 is ready, so at most two tasks' chunks are on the device.
+        Device-tier arguments are passed whole.  ``reduce`` outputs start
+        at the operator's identity and are combined on the device, task by
+        task, in one jitted program per chunk shape (``_stream_step``).
+        Host-tier arguments are read-only, one disjoint region per task;
+        any other written argument must be a ``reduce`` of the whole array
+        in every task."""
+        ann = kernel.annotation
+        host = {n: a for n, a in args.items() if a.tier == HOST}
+        for stmt in ann.stmts:
+            if stmt.array in host and stmt.mode != READ:
+                raise NotImplementedError(
+                    f"{kernel.name}: {stmt.mode} access to host-resident "
+                    f"{stmt.array!r}; a streamed launch only reads the host "
+                    "tier")
+            if stmt.writes and stmt.mode != MODE_REDUCE:
+                raise NotImplementedError(
+                    f"{kernel.name}: {stmt.mode} of {stmt.array!r} in a "
+                    "launch that streams a host-resident argument; only "
+                    "reduce outputs are supported")
+        envs = [ann.env_for_superblock(t.region, block_shape=block_shape)
+                for t in tasks]
+        regions = {}  # host-tier argument -> its read region, task by task
+        for n, a in host.items():
+            regions[n] = [ann.stmt_for(n).region(env, a.shape) for env in envs]
+            for prev, region in zip(regions[n], regions[n][1:]):
+                if prev.overlaps(region):
+                    raise NotImplementedError(
+                        f"{kernel.name}: superblocks read overlapping regions "
+                        f"of host-resident {n!r} (a halo or whole-array "
+                        "read); a streamed launch reads each region once")
+        ops = tuple((s.array, s.reduce_op or "+") for s in ann.stmts
+                    if s.mode == MODE_REDUCE)
+        for n, _ in ops:
+            whole = Region.from_shape(args[n].shape)
+            if any(ann.stmt_for(n).region(env, args[n].shape) != whole
+                   for env in envs):
+                raise NotImplementedError(
+                    f"{kernel.name}: reduce into a part of {n!r} that "
+                    "follows the superblock; a streamed launch combines "
+                    "whole-array reduce outputs only")
+        acc = {n: jnp.full(args[n].shape, identity_for(op, args[n].dtype),
+                           device=self.device) for n, op in ops}
+        resident = {n: a.value for n, a in args.items() if n not in host}
+        dynamic = {k: v for k, v in scalars.items() if isinstance(v, jax.Array)}
+        static = tuple(sorted((k, v) for k, v in scalars.items()
+                              if k not in dynamic))
+
+        inflight = self._streaming
+        wait_s, h2d_bytes = 0.0, 0
+
+        def put(i: int, keep: int) -> dict[str, jax.Array]:
+            nonlocal wait_s, h2d_bytes
+            t0 = time.perf_counter()
+            while len(inflight) > keep:  # the device holds two chunks at most
+                chunk, done = inflight.popleft()
+                jax.block_until_ready(done)
+                for x in chunk:
+                    x.delete()
+            wait_s += time.perf_counter() - t0
+            parts = {n: a.value[regions[n][i].to_slices()]
+                     for n, a in host.items()}
+            h2d_bytes += sum(p.nbytes for p in parts.values())
+            return {n: _put_pieces(p, self.device) for n, p in parts.items()}
+
+        traced = self.tracer.enabled
+        with (self.tracer.span(f"stream:{kernel.name}", stream="driver",
+                               cat="transfer", launch=lid)
+              if traced else _NULL_SPAN) as stream_span:
+            chunk = put(0, keep=1)
+            for i, task in enumerate(tasks):
+                nxt = put(i + 1, keep=0) if i + 1 < len(tasks) else None
+                acc = _stream_step(
+                    acc, np.asarray(task.region.starts, np.int32), resident,
+                    chunk, dynamic, kernel=kernel, grid=grid,
+                    local_shape=task.region.shape, ops=ops, static=static)
+                inflight.append((sum(chunk.values(), ()), acc))
+                chunk = nxt
+            if traced:
+                stream_span.add(chunks=len(tasks), bytes=h2d_bytes,
+                                wait_s=wait_s)
+        registry = self.registry
+        registry.counter("launch.h2d_bytes").labels(
+            kernel=kernel.name).inc(h2d_bytes)
+        registry.counter("launch.chunks").labels(
+            kernel=kernel.name).inc(len(tasks))
+        return acc
+
     # -- mesh execution --------------------------------------------------------------
 
     def _execute_mesh(
@@ -457,6 +639,62 @@ class Context:
 #: Most jitted mesh programs one :class:`Context` keeps (least recently used
 #: dropped first): a bound for callers that make a new kernel every launch.
 _MESH_PROGRAMS = 64
+
+#: Pieces a streamed chunk is cut into along its first axis, each at least
+#: ``_PUT_MIN_BYTES``, and put on the device concurrently: one put of a
+#: large narrow array is bound by one host thread's copy into the device's
+#: layout, which the host's other load slows.
+_PUT_SPLIT = 4
+_PUT_MIN_BYTES = 32 << 20
+
+
+@functools.cache
+def _put_pool() -> concurrent.futures.ThreadPoolExecutor:
+    return concurrent.futures.ThreadPoolExecutor(
+        _PUT_SPLIT, thread_name_prefix="repro-h2d")
+
+
+def _put_pieces(part: np.ndarray, device: jax.Device) -> tuple[jax.Array, ...]:
+    """``part`` on ``device``, as up to ``_PUT_SPLIT`` pieces along its
+    first axis put concurrently (``_stream_step`` joins them)."""
+    k = max(1, min(_PUT_SPLIT, part.nbytes // _PUT_MIN_BYTES, len(part)))
+    if k == 1:
+        return (jax.device_put(part, device),)
+    return tuple(_put_pool().map(lambda p: jax.device_put(p, device),
+                                 np.array_split(part, k)))
+
+
+#: Share of the device budget that the two chunks of a streamed launch may
+#: take, counted in host bytes: 1/8 leaves room for a narrow array's padded
+#: device layout and for the body's temporaries.  For 34-column float32
+#: points on one 16 GB v5e it gives superblocks of 2^22 points (570 MB).
+_STREAM_SHARE = 1 / 8
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "kernel", "grid", "local_shape", "ops", "static"))
+def _stream_step(acc, offset, resident, chunks, dynamic, *, kernel, grid,
+                 local_shape, ops, static):
+    """One task of a streamed launch: the body on one superblock's views,
+    its ``reduce`` partials combined into ``acc``.  ``chunks`` are the
+    host-tier arguments' read regions, each as the pieces it was put in
+    (``_put_pieces``); ``resident`` the device-tier arguments.  ``offset``
+    (the superblock's origin) and ``dynamic`` (the ``jax.Array`` scalars)
+    are traced, so tasks of one shape share one program across launches
+    and contexts; ``static`` holds the other
+    scalars as ``(name, value)`` pairs, which must be hashable."""
+    views = {**resident, **{n: p[0] if len(p) == 1 else jnp.concatenate(p)
+                            for n, p in chunks.items()}}
+    info = SuperblockInfo(
+        grid=grid,
+        thread_offset=tuple(offset[d] for d in range(len(grid))),
+        local_shape=local_shape,
+        device_index=0,
+        scalars={**dict(static), **dynamic},
+    )
+    outs = kernel.body(views, info)
+    return {n: combine(op, acc[n], outs[n].astype(acc[n].dtype))
+            for n, op in ops}
 
 
 def _mesh_program(

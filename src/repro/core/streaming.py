@@ -7,10 +7,11 @@ the device with double buffering: while chunk *i* computes, chunk *i+1* is
 already being transferred (`jax.device_put` is async), so transfer and
 compute overlap exactly like the paper's memory-manager pipeline.
 
-``stream_map_reduce`` is the executable form of the paper's K-Means /
-Black-Scholes streaming experiments: a per-chunk kernel plus a running
-reduction, with a working set of exactly two chunks regardless of the
-total data size.
+``stream_map_reduce`` folds a per-chunk kernel over host chunks with a
+working set of exactly two chunks regardless of the total data size.
+``stream_kmeans``, the paper's K-Means spilling experiment, is a launch
+through :class:`~repro.core.launch.Context` with the points on the host
+tier: the same streaming, planned in superblocks by Lightning's planner.
 """
 
 from __future__ import annotations
@@ -20,6 +21,11 @@ from typing import Callable, Iterable
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.kernels.kmeans import kmeans_assign_reduce, kmeans_assign_reduce_ref
+
+from .launch import Context, KernelDef
+from .superblock import BlockWork
 
 
 def iter_chunks(array: np.ndarray, chunk_rows: int) -> Iterable[np.ndarray]:
@@ -42,6 +48,10 @@ def stream_map_reduce(
     The final (ragged) chunk is padded to ``chunk_rows`` so the jitted
     kernel compiles once; kernels must be padding-safe (the paper's kernels
     guard with bounds checks; ours use neutral pad values).
+
+    To be removed once out-of-core writes (``black_scholes.spill``) go
+    through ``Context.launch``, whose streamed launch does this fold for
+    reduce outputs.
     """
     kernel = jax.jit(kernel)
     combine = jax.jit(combine)
@@ -73,6 +83,26 @@ def stream_map_reduce(
     return acc
 
 
+_KMEANS_ANNOTATION = ("global i => read points[i,:], read centroids[:,:], "
+                      "reduce(+) sums[:,:], reduce(+) counts[:]")
+
+
+def _kmeans_body(assign):
+    def body(views, _info):
+        sums, counts = assign(views["points"], views["centroids"])
+        return {"sums": sums, "counts": counts}
+    return body
+
+
+#: The assignment kernel by ``use_pallas``; kept, so that every call of
+#: ``stream_kmeans`` runs the same per-chunk programs.
+_KMEANS = {
+    pallas: KernelDef.define("kmeans", _kmeans_body(assign),
+                             _KMEANS_ANNOTATION)
+    for pallas, assign in ((True, kmeans_assign_reduce),
+                           (False, kmeans_assign_reduce_ref))}
+
+
 def stream_kmeans(
     points: np.ndarray,  # (n, f) host-resident, any size
     centroids: jax.Array,  # (k, f) device-resident
@@ -81,34 +111,22 @@ def stream_kmeans(
     use_pallas: bool = True,
 ) -> jax.Array:
     """One K-Means iteration over host-resident data of any size — the
-    paper's flagship spilling experiment (Figs. 10–12), end to end."""
-    from repro.kernels.kmeans import (
-        kmeans_assign_reduce,
-        kmeans_assign_reduce_ref,
-    )
-
-    assign = kmeans_assign_reduce if use_pallas else kmeans_assign_reduce_ref
+    paper's flagship spilling experiment (Figs. 10–12), end to end.  The
+    points stay on the host tier of a :class:`Context` and one launch
+    streams them in superblocks of ``chunk_rows`` points; the centroids are
+    an argument of the launch, so repeated calls compile nothing."""
+    # A budget of 0 keeps the points on the host tier whatever their size:
+    # a narrow (n, f) array may not fit on the device even where its host
+    # bytes do, and the caller asked for them to be streamed.
+    ctx = Context(device_budget=0)
+    centroids = jnp.asarray(centroids)
     k, f = centroids.shape
-
-    def kernel(chunk):
-        sums, counts = assign(chunk, centroids)
-        return jnp.concatenate([sums, counts[:, None]], axis=1)  # (k, f+1)
-
-    def combine(acc, part):
-        return acc + part
-
-    init = jnp.zeros((k, f + 1), jnp.float32)
-    agg = stream_map_reduce(
-        points, kernel, combine, init, chunk_rows=chunk_rows,
-    )
-    sums, counts = agg[:, :f], agg[:, f]
-    # Padding rows are all-zero points: they land in the centroid nearest
-    # the origin; subtract their count.
-    n = points.shape[0]
-    total_rows = -(-n // chunk_rows) * chunk_rows
-    n_pad = total_rows - n
-    if n_pad:
-        j = jnp.argmin(jnp.sum(centroids * centroids, axis=1))
-        counts = counts.at[j].add(-float(n_pad))
-    counts = jnp.maximum(counts, 1.0)
-    return (sums / counts[:, None]).astype(centroids.dtype)
+    res = ctx.launch(
+        _KMEANS[use_pallas], grid=(points.shape[0],),
+        work_dist=BlockWork(chunk_rows),
+        args={"points": ctx.array(points, name="points"),
+              "centroids": ctx.array(centroids, name="centroids"),
+              "sums": ctx.zeros((k, f), name="sums"),
+              "counts": ctx.zeros((k,), name="counts")})
+    counts = jnp.maximum(res["counts"].value, 1.0)
+    return (res["sums"].value / counts[:, None]).astype(centroids.dtype)
